@@ -24,6 +24,7 @@ from repro.core import packing
 from repro.core.heloco import (
     apply_arrival, apply_arrival_packed, block_correct, init_outer_state,
 )
+from repro.kernels.packed import count_launches
 
 H = HeLoCoConfig()
 N_BLOCKS = 8
@@ -49,25 +50,6 @@ def time_correction(d: int, reps: int = 20) -> float:
         out = fn(delta, mom)
     jax.block_until_ready(out)
     return (time.perf_counter() - t0) / reps * 1e6
-
-
-def count_launches(fn, *args) -> int:
-    """pallas_call equation instances in the traced program — the number
-    of kernel dispatches one execution performs (trace-time interception
-    undercounts: same-shape blocks share a jit cache entry)."""
-    def walk(jx) -> int:
-        n = 0
-        for eqn in jx.eqns:
-            if eqn.primitive.name == "pallas_call":
-                n += 1
-            for v in eqn.params.values():
-                for sub in (v if isinstance(v, (tuple, list)) else (v,)):
-                    if isinstance(sub, jax.core.ClosedJaxpr):
-                        n += walk(sub.jaxpr)
-                    elif isinstance(sub, jax.core.Jaxpr):
-                        n += walk(sub)
-        return n
-    return walk(jax.make_jaxpr(fn)(*args).jaxpr)
 
 
 def _time_jit(fn, *args, reps: int = 30) -> float:

@@ -43,7 +43,8 @@ from typing import Dict, List
 import jax
 import jax.numpy as jnp
 
-from benchmarks.bench_overhead import N_BLOCKS, _blocks, count_launches
+from benchmarks.bench_overhead import N_BLOCKS, _blocks
+from repro.kernels.packed import count_launches
 from repro.configs.base import HeLoCoConfig, OuterOptConfig
 from repro.core import packing
 from repro.core.heloco import apply_arrivals_packed

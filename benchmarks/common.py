@@ -16,6 +16,8 @@ import os
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
+
 from repro.configs.base import RunConfig
 from repro.core import methods as outer_methods
 from repro.async_engine.engine import make_engine, make_eval_fn
@@ -80,6 +82,9 @@ def _key(rc: RunConfig, eval_every: int, engine: str = "sim",
         tag += f"|budget:{budget.kind}:{budget.amount}"
     if telemetry:
         tag += "|telem"
+    # a result is only ever served back on the device kind it ran on
+    dev = jax.devices()[0]
+    tag += f"|{dev.platform}:{dev.device_kind}"
     return hashlib.sha1((blob + str(eval_every) + tag).encode()
                         ).hexdigest()[:16]
 

@@ -18,6 +18,8 @@ import os
 import sys
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
+
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # Canonical location: results/ (one place for CI artifacts + local runs).
 BENCH_DIR = os.environ.get("REPRO_BENCH_DIR",
@@ -67,6 +69,7 @@ def main() -> None:
                          "contracts, N in {64,1k,10k} bookkeeping, "
                          "transfer probe) -> BENCH_scale.json")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.scale:
         from benchmarks import bench_scale

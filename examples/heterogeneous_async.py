@@ -5,10 +5,10 @@ and Dirichlet language mixtures. Runs are described as
 ``repro.scenarios`` specs — the same source of truth as the launcher and
 the golden-trace CI gate; ``--scenario NAME`` replays a registered one.
 
-    PYTHONPATH=src python examples/heterogeneous_async.py \
+    PYTHONPATH=src:. python examples/heterogeneous_async.py \
         --paces 1,1,6,6,6 --methods async-heloco,async-mla --outer 30 \
         --engine wallclock
-    PYTHONPATH=src python examples/heterogeneous_async.py \
+    PYTHONPATH=src:. python examples/heterogeneous_async.py \
         --scenario paper_hetero_severe
 """
 import argparse

@@ -652,6 +652,15 @@ class WorkerProcessPool:
                  hb_sink: Optional[Transport] = None,
                  family: Optional[str] = None,
                  obs: bool = False, obs_every: int = 4):
+        if jax.default_backend() != "cpu":
+            # every worker process would open the accelerator the parent
+            # already holds; one process drives all local chips instead
+            raise RuntimeError(
+                f"transport='socket' runs one JAX process per worker and "
+                f"needs the CPU backend; this process holds "
+                f"{jax.default_backend()!r}, whose chips belong to one "
+                f"process at a time. Use transport='inproc', which runs "
+                f"every worker in this process (one per chip).")
         self.run_cfg = run_cfg
         self.faults = faults
         self.mode = mode
@@ -765,9 +774,10 @@ class WorkerProcessPool:
             self._pending[nonce] = (wid, inc)
             ready = threading.Event()
             self._ready[(wid, inc)] = ready
-        # children must see the parent's backend: spawn inherits the env
+        # children run on the CPU backend, as the parent does (see
+        # __init__); spawn inherits the env
         prev = os.environ.get("JAX_PLATFORMS")
-        os.environ["JAX_PLATFORMS"] = prev or jax.default_backend()
+        os.environ["JAX_PLATFORMS"] = "cpu"
         try:
             proc = self._ctx.Process(target=_worker_main,
                                      args=(self.transport.address, nonce),

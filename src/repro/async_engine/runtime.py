@@ -64,7 +64,7 @@ from __future__ import annotations
 import queue as _queue
 import threading
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -188,6 +188,8 @@ class ConcurrentRuntime(EngineBase):
             "overlap_samples": [], "compute_seconds_total": 0.0,
         }
         devices = jax.devices()
+        # the server state lives on the default device, devices[0]
+        self._server_device = devices[0]
         if pin_devices and len(devices) > 1 and self.transport_kind != "socket":
             for w in self.workers.values():
                 w.device = devices[w.wid % len(devices)]
@@ -268,11 +270,9 @@ class ConcurrentRuntime(EngineBase):
             with self._comp_lock:
                 self._computing += 1
             try:
-                if task.device is not None:
-                    with jax.default_device(task.device):
-                        out: Any = self._execute(task)
-                else:
-                    out = self._execute(task)
+                out: Any = (self._execute_pinned(task)
+                            if task.device is not None
+                            else self._execute(task))
             except Exception as e:                      # noqa: BLE001
                 out = RoundError(task.wid, task.generation,
                                  task.round_seq, repr(e))
@@ -298,6 +298,21 @@ class ConcurrentRuntime(EngineBase):
                                crc=payload_crc(out))
             if not self._send_reliably(env, waiter):
                 return                              # channel torn down
+
+    def _execute_pinned(self, task: RoundTask) -> RoundResult:
+        """Run a round on the worker's own device. Committed arrays
+        override ``jax.default_device``, so the round's params, optimiser
+        state and error feedback are first placed on that device; the
+        delta then goes back to the server's device, where it is
+        committed."""
+        dev = task.device
+        task = replace(task, params=jax.device_put(task.params, dev),
+                       opt=jax.device_put(task.opt, dev),
+                       ef=jax.device_put(task.ef, dev))
+        with jax.default_device(dev):
+            res = self._execute(task)
+        res.delta = jax.device_put(res.delta, self._server_device)
+        return res
 
     def _send_reliably(self, env: Envelope, waiter: AckWaiter) -> bool:
         """At-least-once send via the shared ``ReliableSender`` (the same

@@ -83,7 +83,7 @@ def correct_block(delta: jnp.ndarray, mom: jnp.ndarray,
     safe_nv = jnp.maximum(nv, h.eps)
     u_hat = u / safe_nu
     v_hat = v / safe_nv
-    c = jnp.dot(u_hat, v_hat)                                     # Eq. 8
+    c = jnp.dot(u_hat, v_hat, precision="highest")                # Eq. 8
     conf = nu / (nu + h.kappa * nv + h.eps)                       # Eq. 15
 
     # anti-aligned branch (Eq. 10-11)

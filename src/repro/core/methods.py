@@ -392,7 +392,9 @@ def _heloco_multi_coeffs(m, ctxs, dstack, mbuf):
         e = j + 1                                   # basis slot of d_j
         dot = jnp.sum(alpha * gram[:, e, :], axis=1)
         uu = gram[:, e, e]
-        vv = jnp.sum(alpha * jnp.einsum("btu,bu->bt", gram, alpha), axis=1)
+        # full fp32: at default precision the TPU contracts f32 in bf16
+        vv = jnp.sum(alpha * jnp.einsum("btu,bu->bt", gram, alpha,
+                                        precision="highest"), axis=1)
         cu, cv = pk.branch_scalars(jnp.stack([dot, uu, vv], axis=1), ctx.h)
         cus.append(cu)
         cvs.append(cv)

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 from repro.configs.base import HeLoCoConfig
 from repro.kernels.tiling import LANES, row_tile
@@ -764,3 +765,26 @@ def packed_dequant(q2d: jnp.ndarray, scale_rows: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct(q2d.shape, out_dtype),
         interpret=interpret,
     )(q2d, scale_rows)
+
+
+# ---------------------------------------------------------------------------
+# Launch accounting
+# ---------------------------------------------------------------------------
+
+def count_launches(fn, *args) -> int:
+    """``pallas_call`` equations in the traced program of ``fn(*args)``,
+    nested jaxprs included: the kernel dispatches one execution performs
+    (robust to jit caching across same-shape blocks)."""
+    def walk(jx) -> int:
+        n = 0
+        for eqn in jx.eqns:
+            if eqn.primitive.name == "pallas_call":
+                n += 1
+            for v in eqn.params.values():
+                for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                    if isinstance(sub, ClosedJaxpr):
+                        n += walk(sub.jaxpr)
+                    elif isinstance(sub, Jaxpr):
+                        n += walk(sub)
+        return n
+    return walk(jax.make_jaxpr(fn)(*args).jaxpr)

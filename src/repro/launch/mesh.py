@@ -10,30 +10,13 @@ jax device state.
 """
 from __future__ import annotations
 
-import contextlib
-
 import jax
 
 
 def _mesh(shape, axes):
-    # jax >= 0.6 takes axis_types (Auto lets GSPMD infer intermediate
-    # shardings); 0.4.x has neither the kwarg nor the enum — its meshes
-    # are implicitly auto.
-    axis_type = getattr(getattr(jax.sharding, "AxisType", None), "Auto", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def mesh_context(mesh):
-    """Ambient-mesh context across jax versions: ``jax.set_mesh`` (>= 0.6)
-    or the ``Mesh`` context manager (0.4.x) — both make bare
-    ``PartitionSpec`` constraints resolve against ``mesh``."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh if hasattr(mesh, "__enter__") else contextlib.nullcontext()
+    # Auto axes let GSPMD infer intermediate shardings
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -33,6 +33,7 @@ from repro.checkpoint import ckpt as ckpt_lib
 from repro.core import methods as outer_methods
 from repro.async_engine.engine import make_engine, make_eval_fn
 from repro.async_engine.faults import FaultSpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.scenarios import registry
 from repro.scenarios.spec import Scenario
 
@@ -171,6 +172,7 @@ def main():
             print(f"{s.name:24s} engine={s.engine}/{s.mode}  "
                   f"{s.description}")
         return
+    enable_compile_cache()
 
     if args.scenario:
         scn = registry.get_scenario(args.scenario)

@@ -148,3 +148,64 @@ def test_flexible_assignment_balances_langs():
     sim.run()
     toks = sim.lang_tokens[sim.lang_tokens > 0]
     assert toks.max() <= toks.min() * 4  # far tighter than fixed w/ 8x pace gap
+
+
+def test_socket_transport_refuses_an_accelerator_backend(monkeypatch):
+    """A chip belongs to one process: worker processes on an accelerator
+    host would each reach for the chip the parent holds."""
+    from repro.async_engine import proc
+    monkeypatch.setattr(proc.jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="transport='inproc'"):
+        proc.WorkerProcessPool(tiny_run(n_workers=2))
+
+
+_PINNED_SCRIPT = r"""
+import jax
+from repro.async_engine.engine import make_engine
+from repro.scenarios.spec import Scenario
+
+scn = Scenario(name="pinned", engine="wallclock", n_workers=4,
+               worker_paces=(1.0, 2.0, 3.0, 4.0), inner_steps=1,
+               outer_steps=8, batch_size=2, seq_len=16)
+m = scn.materialize()
+devs = jax.devices()
+for pin in (True, False):
+    eng = make_engine(m.run_cfg, m.engine, pin_devices=pin, **m.engine_kw)
+    made, committed = {}, set()
+    execute, commit = eng._execute, eng.server._step_update
+
+    def record(task, execute=execute, made=made):
+        res = execute(task)
+        made.setdefault(task.wid, set()).update(
+            jax.tree.leaves(res.delta)[0].devices())
+        return res
+
+    def spy(delta, rho, tau, commit=commit, committed=committed):
+        committed.update(jax.tree.leaves(delta)[0].devices())
+        return commit(delta, rho, tau)
+
+    eng._execute, eng.server._step_update = record, spy
+    hist = eng.run()
+    assert sorted(a["worker_id"] for a in hist.arrivals) == \
+        [0, 0, 0, 0, 1, 1, 2, 3], hist.arrivals
+    want = ({w: {devs[w]} for w in range(4)} if pin
+            else {w: {devs[0]} for w in range(4)})
+    assert made == want, (pin, made)
+    assert committed == {devs[0]}, (pin, committed)
+print("PINNED_OK")
+"""
+
+
+def test_pinned_workers_run_on_their_devices_and_commit_on_the_server():
+    """One worker per device: each round runs on its worker's device and
+    its delta is committed on the server's (4 host devices in a child
+    process, so this process keeps its one-device view)."""
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.path.join(root, "src"))
+    out = subprocess.run([sys.executable, "-c", _PINNED_SCRIPT], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert "PINNED_OK" in out.stdout, out.stderr[-3000:]

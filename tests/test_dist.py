@@ -22,7 +22,7 @@ from repro.configs.base import HeLoCoConfig, InnerOptConfig
 from repro.dist import sharding as shd
 from repro.dist.steps import (init_train_state, make_multipod_train_step,
                               make_outer_exchange, make_train_step)
-from repro.launch.mesh import make_test_mesh, mesh_context
+from repro.launch.mesh import make_test_mesh
 from repro.core.heloco import OuterState, block_correct, outer_update, lookahead_init
 from repro.models import build_model
 
@@ -45,7 +45,7 @@ state2 = stack(state)
 tok = jax.random.randint(jax.random.PRNGKey(1), (2, 4, 16), 0, cfg.vocab_size)
 batch_same = {"tokens": tok[:1].repeat(2, 0), "labels": tok[:1].repeat(2, 0)}
 batch_diff = {"tokens": tok, "labels": tok}
-with mesh_context(mesh):
+with jax.set_mesh(mesh):
     ns, loss = jax.jit(step)(state2, batch_same)
     leaf = jax.tree.leaves(ns.params)[0]
     np.testing.assert_array_equal(np.asarray(leaf[0]), np.asarray(leaf[1]))
@@ -63,7 +63,7 @@ wp = jax.tree.map(lambda x: jnp.stack([x - 0.05, x + 0.02]), params)
 fn = make_outer_exchange(cfg, mesh, h=h, outer_lr=0.7, mu=0.9,
                          method="heloco", arriving_pod=1,
                          stacked_axes=stacked)
-with mesh_context(mesh):
+with jax.set_mesh(mesh):
     new_p, new_m, bar = jax.jit(fn)(params, mom, wp)
 # reference: delta from pod 1 only
 delta_ref = jax.tree.map(
@@ -84,7 +84,7 @@ print("EXCHANGE_OK")
 fn8 = make_outer_exchange(cfg, mesh, h=h, outer_lr=0.7, mu=0.9,
                           method="heloco", arriving_pod=1,
                           stacked_axes=stacked, compress_int8=True)
-with mesh_context(mesh):
+with jax.set_mesh(mesh):
     p8, m8, _ = jax.jit(fn8)(params, mom, wp)
 num = den = 0.0
 for a, b in zip(jax.tree.leaves(p8), jax.tree.leaves(new_p)):

@@ -7,7 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from repro.utils.hypcompat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs.base import HeLoCoConfig
 from repro.kernels import ops

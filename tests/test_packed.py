@@ -15,6 +15,7 @@ from repro.core.heloco import (
 )
 from repro.async_engine.server import Synchronizer
 from repro.kernels import ops
+from repro.kernels import packed as pk
 from repro.kernels.tiling import LANES, ROW_ALIGN, ROWS, padded_rows, row_tile
 
 H = HeLoCoConfig()
@@ -208,7 +209,6 @@ def test_multi_step_grid_matches_single_step():
     exercises every kernel's index maps."""
     from repro.kernels import heloco_correct as hk
     from repro.kernels import outer_update as ok
-    from repro.kernels import packed as pk
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     r = 64
     u = jax.random.normal(ks[0], (r, LANES))
@@ -247,24 +247,6 @@ def test_multi_step_grid_matches_single_step():
 # O(1) kernel launches per arrival
 # ---------------------------------------------------------------------------
 
-def _count_launches(fn, *args):
-    """pallas_call equation instances in the traced program (= dispatches
-    per execution; robust to jit caching across same-shape blocks)."""
-    def walk(jx):
-        n = 0
-        for eqn in jx.eqns:
-            if eqn.primitive.name == "pallas_call":
-                n += 1
-            for v in eqn.params.values():
-                for sub in (v if isinstance(v, (tuple, list)) else (v,)):
-                    if isinstance(sub, jax.core.ClosedJaxpr):
-                        n += walk(sub.jaxpr)
-                    elif isinstance(sub, jax.core.Jaxpr):
-                        n += walk(sub)
-        return n
-    return walk(jax.make_jaxpr(fn)(*args).jaxpr)
-
-
 def test_packed_arrival_is_two_launches():
     params = _tree(jax.random.PRNGKey(5))
     delta = _tree(jax.random.PRNGKey(6))
@@ -272,7 +254,7 @@ def test_packed_arrival_is_two_launches():
     pbuf = packing.pack(layout, params)
     mbuf = packing.zeros(layout)
 
-    n_packed = _count_launches(
+    n_packed = pk.count_launches(
         lambda: apply_arrival_packed(pbuf, mbuf, delta, layout,
                                      method="heloco", outer_lr=0.7, mu=0.9,
                                      h=H))
@@ -280,7 +262,7 @@ def test_packed_arrival_is_two_launches():
 
     # per-leaf kernel path: 2 launches per block, independent of d
     state = init_outer_state(params)
-    n_leaf = _count_launches(
+    n_leaf = pk.count_launches(
         lambda: apply_arrival(state, delta, method="heloco", outer_lr=0.7,
                               mu=0.9, h=H, stacked_axes=STACKED,
                               use_kernel=True))

@@ -1,5 +1,7 @@
 """End-to-end behaviour tests for the HeLoCo system: the paper's headline
 qualitative claims on a tiny model, plus config registry integrity."""
+import os
+
 import numpy as np
 import pytest
 
@@ -88,3 +90,37 @@ def test_lookahead_init_helps_or_neutral():
     on = run_cached("sys_lookahead_on", rc_on)
     off = run_cached("sys_lookahead_off", rc_off)
     assert on["final_loss"] <= off["final_loss"] + 0.15
+
+
+def test_compile_cache_dir_is_fixed_or_taken_from_the_environment(
+        monkeypatch):
+    import jax
+    from repro.launch import compile_cache
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        jax.config.update("jax_compilation_cache_dir", prev)
+        assert compile_cache.enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == prev
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.enable_compile_cache()
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert path == os.path.join(root, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_run_cache_key_names_the_device(monkeypatch):
+    """A result cached on one device kind is never served on another."""
+    import jax
+    from benchmarks import common
+
+    class Fake:
+        platform, device_kind = "tpu", "TPU v5 lite"
+
+    rc = common.base_run((1.0, 2.0), method="heloco", non_iid=True,
+                         outer_steps=2, inner_steps=1)
+    here = common._key(rc, 0)
+    monkeypatch.setattr(common.jax, "devices", lambda: [Fake()])
+    assert common._key(rc, 0) != here
