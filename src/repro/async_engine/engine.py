@@ -46,7 +46,7 @@ import numpy as np
 from repro.checkpoint import ckpt
 from repro.configs.base import RunConfig
 from repro.core.compression import roundtrip_with_error_feedback
-from repro.obs.spans import NULL_TRACER
+from repro.obs.spans import NULL_TRACER, program_build_listener
 from repro.async_engine.server import Synchronizer
 from repro.data.synthetic import (
     ShardSampler, eval_batches, make_language_specs, mixture_weights,
@@ -489,8 +489,10 @@ def execute_round(task: RoundTask, *, model, cfg: RunConfig, specs,
                                mixture=task.mixture)
         result = run_inner(model, cfg.inner, task.params,
                            task.opt, sampler, task.h_steps,
-                           step_offset=task.inner_step_offset)
-        delta = pseudo_gradient(task.params, result.params)
+                           step_offset=task.inner_step_offset,
+                           tracer=tracer)
+        with tracer.span("pseudo_gradient", cat="compute"):
+            delta = pseudo_gradient(task.params, result.params)
     # int8 rides the server's packed layout: per-block scales, O(1)
     # kernel launches, and a packed error-feedback buffer per worker.
     with tracer.span("compress_roundtrip", cat="compute", wid=task.wid):
@@ -654,24 +656,25 @@ class EngineBase:
     def _make_task(self, w: Worker) -> RoundTask:
         """Capture the worker's initialization + round snapshot (server
         thread only — reads Synchronizer state and shard accounting)."""
-        w.params = jax.tree.map(jnp.copy, self.server.worker_init(w.wid))
-        w.s_i = self.server.t
-        w.h_steps = self._h_steps(w)
-        w.cur_lang = self._pick_lang(w)
-        w.dispatch_time = self.time
-        w.round_seq += 1
-        w.in_flight = True
-        self._task_counter += 1
-        w.pending_task_id = self._task_counter
-        return RoundTask(
-            task_id=self._task_counter,
-            wid=w.wid, generation=w.generation, round_seq=w.round_seq,
-            params=w.params, opt=w.opt, ef=w.ef, s_i=w.s_i,
-            h_steps=w.h_steps, lang=w.cur_lang, mixture=w.mixture,
-            inner_step_offset=w.inner_step_count,
-            dispatch_time=self.time,
-            sleep_per_step=self._sleep_per_step(w), device=w.device,
-            batch_size=self._round_batch())
+        with self.tracer.span("round_dispatch", cat="server", wid=w.wid):
+            w.params = jax.tree.map(jnp.copy, self.server.worker_init(w.wid))
+            w.s_i = self.server.t
+            w.h_steps = self._h_steps(w)
+            w.cur_lang = self._pick_lang(w)
+            w.dispatch_time = self.time
+            w.round_seq += 1
+            w.in_flight = True
+            self._task_counter += 1
+            w.pending_task_id = self._task_counter
+            return RoundTask(
+                task_id=self._task_counter,
+                wid=w.wid, generation=w.generation, round_seq=w.round_seq,
+                params=w.params, opt=w.opt, ef=w.ef, s_i=w.s_i,
+                h_steps=w.h_steps, lang=w.cur_lang, mixture=w.mixture,
+                inner_step_offset=w.inner_step_count,
+                dispatch_time=self.time,
+                sleep_per_step=self._sleep_per_step(w), device=w.device,
+                batch_size=self._round_batch())
 
     def _round_batch(self) -> int:
         """Per-round mini-batch under the hogwild ramp-up schedule
@@ -843,6 +846,23 @@ class EngineBase:
             ckpt_every: int = 0, ckpt_dir: str = "",
             budget: Optional[Budget] = None) -> History:
         self._ensure_telemetry_meta()
+        # with tracing on, every lowering of a program in the process
+        # becomes a ``program_build`` span of this engine's tracer
+        listener = None
+        if self.tracer.enabled:
+            listener = program_build_listener(self.tracer)
+            jax.monitoring.register_event_duration_secs_listener(listener)
+        try:
+            return self._loop(eval_every, eval_fn, ckpt_every, ckpt_dir,
+                              budget)
+        finally:
+            if listener is not None:
+                jax.monitoring.unregister_event_duration_listener(listener)
+
+    def _loop(self, eval_every, eval_fn, ckpt_every, ckpt_dir,
+              budget: Optional[Budget] = None) -> History:
+        """The run loop of the job's method; the concurrent runtime adds
+        its free-running loop."""
         if self.server.method.sync:
             return self._run_sync(eval_every, eval_fn, ckpt_every, ckpt_dir,
                                   budget)

@@ -826,16 +826,20 @@ class ConcurrentRuntime(EngineBase):
         t0 = time.monotonic()
         self._run_t0 = t0
         try:
-            if self.mode == "free" and not self.server.method.sync:
-                hist = self._run_free(eval_every, eval_fn, ckpt_every,
-                                      ckpt_dir, budget)
-            else:
-                hist = super().run(eval_every, eval_fn, ckpt_every, ckpt_dir,
-                                   budget)
+            hist = super().run(eval_every, eval_fn, ckpt_every, ckpt_dir,
+                               budget)
         finally:
             self.stats["wall_seconds"] += time.monotonic() - t0
             self.shutdown()
         return hist
+
+    def _loop(self, eval_every, eval_fn, ckpt_every, ckpt_dir,
+              budget=None) -> History:
+        if self.mode == "free" and not self.server.method.sync:
+            return self._run_free(eval_every, eval_fn, ckpt_every, ckpt_dir,
+                                  budget)
+        return super()._loop(eval_every, eval_fn, ckpt_every, ckpt_dir,
+                             budget)
 
     def _finalize(self, eval_fn) -> History:
         hist = super()._finalize(eval_fn)
@@ -857,7 +861,6 @@ class ConcurrentRuntime(EngineBase):
         on committed tokens (fixed_tokens). Heartbeat liveness runs here:
         every loop iteration drains the side channel and sweeps for
         silent workers."""
-        self._ensure_telemetry_meta()
         target = self.cfg.outer_steps
         self._free_t0 = t0 = time.monotonic()
         scale = self.pace_scale if self.pace_scale > 0 else 1.0

@@ -28,6 +28,7 @@ For the production-mesh lower/compile pass defer to repro.launch.dryrun
 from __future__ import annotations
 
 import argparse
+import contextlib
 
 from repro.checkpoint import ckpt as ckpt_lib
 from repro.core import methods as outer_methods
@@ -129,6 +130,11 @@ def main():
                     help="profile the run with trace spans and export "
                          "Chrome trace-event JSON (Perfetto-loadable) "
                          "to this path")
+    ap.add_argument("--profile", default="", metavar="DIR",
+                    help="capture the run with jax.profiler into DIR "
+                         "(TensorBoard / Perfetto); implies trace spans, "
+                         "which land on the capture's host plane above "
+                         "the device ops")
     ap.add_argument("--stats-json", default="", metavar="PATH",
                     help="dump the runtime stats_summary() as JSON at "
                          "exit (machine-readable CI artifact)")
@@ -192,7 +198,7 @@ def main():
         from repro.telemetry import TelemetryRecorder
         recorder = TelemetryRecorder(sink=args.telemetry)
     tracer = None
-    if args.trace:
+    if args.trace or args.profile:
         from repro.obs.spans import SpanTracer
         tracer = SpanTracer()
     # runtime-health cadence: explicit flag > "on" whenever telemetry is
@@ -209,9 +215,19 @@ def main():
             print(f"resumed from {latest} (outer step {eng.server.t})")
 
     eval_fn = make_eval_fn(eng, batch=scn.eval_batch)
-    hist = eng.run(eval_every=eval_every, eval_fn=eval_fn,
-                   ckpt_every=args.ckpt_every if args.ckpt_dir else 0,
-                   ckpt_dir=args.ckpt_dir)
+    capture = contextlib.nullcontext()
+    if args.profile:
+        import jax
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0     # program spans, not every call
+        capture = jax.profiler.trace(args.profile, profiler_options=opts)
+    with capture:
+        hist = eng.run(eval_every=eval_every, eval_fn=eval_fn,
+                       ckpt_every=args.ckpt_every if args.ckpt_dir else 0,
+                       ckpt_dir=args.ckpt_dir)
+    if args.profile:
+        print(f"profile -> {args.profile} (load in TensorBoard or "
+              f"https://ui.perfetto.dev)")
     for e in hist.evals:
         print(f"step {e['step']:5d}  t={e['time']:8.0f}s  "
               f"loss={e['mean']:.4f}")
@@ -256,7 +272,7 @@ def main():
         print(f"telemetry -> {args.telemetry}: {t['arrivals']} arrivals "
               f"mean_cos={t['mean_cos_align']:.3f} "
               f"mean_corrected_frac={t['mean_corrected_frac']:.3f}")
-    if tracer is not None:
+    if args.trace:
         path = tracer.write(args.trace)
         print(f"trace -> {path}: {len(tracer)} events (load in "
               f"https://ui.perfetto.dev or chrome://tracing)")

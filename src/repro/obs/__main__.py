@@ -6,7 +6,9 @@ Subcommands:
   web <stream.jsonl> [--port N|--snapshot]       web dashboard (stdlib
                                                  http.server + SSE) or
                                                  headless panels JSON
-  trace --validate <trace.json>                  trace-event JSON check
+  trace --validate <trace.json>                  trace-event JSON check,
+                                                 span totals and program
+                                                 builds by function
   record <scenario> --out <stream.jsonl>         run a scenario with a
                                                  live telemetry sink
                                                  (regenerates the
@@ -21,7 +23,7 @@ from __future__ import annotations
 import argparse
 import json
 import sys
-from collections import defaultdict
+from collections import Counter, defaultdict
 from typing import List, Optional
 
 USAGE = __doc__
@@ -55,6 +57,13 @@ def _trace_main(argv: List[str]) -> int:
     for name, (n, total_us) in sorted(by_name.items(),
                                       key=lambda kv: -kv[1][1]):
         print(f"  {name:<24} x{n:<6d} total {total_us / 1e3:9.2f} ms")
+    # a lowering per program_build span: retraces show up by function
+    builds = Counter((e.get("args") or {}).get("fun", "?") for e in spans
+                     if e.get("name") == "program_build")
+    if builds:
+        print(f"program builds by function ({sum(builds.values())}):")
+        for fun, n in builds.most_common():
+            print(f"  {fun:<40} x{n}")
     if problems:
         print(f"INVALID: {len(problems)} problem(s)", file=sys.stderr)
         for p in problems:

@@ -21,8 +21,27 @@ Tracing must never perturb the run it observes:
     (GIL-atomic, so worker threads record without taking a lock); the
     JSON encode cost is paid once at ``write``, never during the run.
 
-Nothing here touches jax — telemetry+tracing-on runs stay byte-identical
-to the committed golden traces (asserted in tests/test_obs.py).
+The profiler's clock
+--------------------
+
+An enabled span also enters ``jax.profiler.TraceAnnotation(name)``, so a
+``jax.profiler`` capture of the run (``launch/train.py --profile DIR``)
+holds every program span on its ``/host:CPU`` plane, on the clock of
+the device ops. With no capture running the annotation is a no-op call,
+about 0.8 us a span. Importing this module does not import jax (the
+``python -m repro.obs`` readers stay pure-Python); the annotation is
+bound when a ``SpanTracer`` is built. Tracing-on runs stay
+byte-identical to the committed golden traces (asserted in
+tests/test_obs.py).
+
+Program builds
+--------------
+
+``program_build_listener`` turns each lowering of a jaxpr to an MLIR
+module (a jit cache miss, whether the persistent cache then hits or
+not) into a zero-length ``program_build`` span carrying the function's
+name. The engines register it for the length of ``run`` when their
+tracer is enabled.
 
     tracer = SpanTracer()
     with tracer.span("worker_round", cat="compute", wid=3):
@@ -38,13 +57,17 @@ import time
 from typing import Any, Dict, List, Optional
 
 __all__ = ["SpanTracer", "NullTracer", "NULL_TRACER",
+           "PROGRAM_BUILD_EVENT", "program_build_listener",
            "validate_chrome_trace"]
+
+#: the ``jax.monitoring`` duration event of one lowering
+PROGRAM_BUILD_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 
 
 class _Span:
     """One live span; created by ``SpanTracer.span`` and finished by the
     ``with`` exit. Re-entrant use is not supported (make a new one)."""
-    __slots__ = ("_tr", "_name", "_cat", "_args", "_t0")
+    __slots__ = ("_tr", "_name", "_cat", "_args", "_t0", "_ann")
 
     def __init__(self, tr: "SpanTracer", name: str, cat: str,
                  args: Optional[Dict[str, Any]]):
@@ -54,11 +77,14 @@ class _Span:
         self._args = args
 
     def __enter__(self) -> "_Span":
+        self._ann = self._tr._annotation(self._name)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
         t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
         tr = self._tr
         ident = threading.get_ident()
         if ident not in tr._names:               # first span on this thread
@@ -110,6 +136,8 @@ class SpanTracer:
     enabled = True
 
     def __init__(self):
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
         self._epoch = time.perf_counter()
         # (name, cat, ph, start_s, dur_s, tid, args) tuples; list.append
         # is GIL-atomic so worker threads record lock-free
@@ -172,11 +200,6 @@ class SpanTracer:
         entry["epoch_offset"] = float(epoch_offset)
         entry["events"].extend(tuple(e) for e in events)
         entry["names"].update({int(k): str(v) for k, v in names.items()})
-
-    @property
-    def pids(self) -> List[int]:
-        """Process rows the merged trace will contain (0 = this one)."""
-        return [0] + sorted(self._foreign)
 
     # -------------------------------------------------------------- export
     def to_chrome(self) -> Dict[str, Any]:
@@ -245,6 +268,18 @@ class SpanTracer:
             json.dump(self.to_chrome(), f)
         os.replace(tmp, path)
         return path
+
+
+def program_build_listener(tracer):
+    """A ``jax.monitoring`` duration listener that records every
+    ``PROGRAM_BUILD_EVENT`` as a zero-length ``program_build`` span on
+    ``tracer``, with the lowered function's name as ``fun``."""
+    def listen(event: str, duration: float, **kw) -> None:
+        if event == PROGRAM_BUILD_EVENT:
+            with tracer.span("program_build", cat="compile",
+                             fun=str(kw.get("fun_name", "?"))):
+                pass
+    return listen
 
 
 # ---------------------------------------------------------------------------

@@ -10,6 +10,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import InnerOptConfig, ModelConfig
 from repro.models import Model
+from repro.obs.spans import NULL_TRACER
 from repro.optim.adamw import AdamState, adamw_update, init_adam
 
 PyTree = Any
@@ -33,17 +34,24 @@ def _jitted_step(model: Model, inner_cfg: InnerOptConfig) -> Callable:
 
 def run_inner(model: Model, inner_cfg: InnerOptConfig, params: PyTree,
               opt: AdamState, sampler, h_steps: int,
-              step_offset: int = 0) -> InnerResult:
-    """H local steps; data drawn from `sampler.sample(step)` per step."""
+              step_offset: int = 0, tracer=NULL_TRACER) -> InnerResult:
+    """H local steps; data drawn from `sampler.sample(step)` per step.
+    ``tracer`` times the copy, and each step's sampling, host-to-device
+    transfer and dispatch (host clock, no device sync)."""
     step_fn = _jitted_step(model, inner_cfg)
     # the caller keeps theta_bar for the pseudo-gradient; the jitted step
     # donates its params buffer, so work on a copy.
-    params = jax.tree.map(jnp.copy, params)
-    opt = jax.tree.map(jnp.copy, opt)
+    with tracer.span("round_copy", cat="compute"):
+        params = jax.tree.map(jnp.copy, params)
+        opt = jax.tree.map(jnp.copy, opt)
     losses = []
     for h in range(h_steps):
-        batch = jax.tree.map(jnp.asarray, sampler.sample(step_offset + h))
-        params, opt, loss = step_fn(params, opt, batch)
+        with tracer.span("batch_sample", cat="input"):
+            batch = sampler.sample(step_offset + h)
+        with tracer.span("batch_to_device", cat="input"):
+            batch = jax.tree.map(jnp.asarray, batch)
+        with tracer.span("inner_dispatch", cat="compute"):
+            params, opt, loss = step_fn(params, opt, batch)
         losses.append(loss)
     return InnerResult(params=params, opt=opt, losses=jnp.stack(losses))
 

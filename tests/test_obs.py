@@ -5,16 +5,19 @@ Chrome trace-event export, the operator console's headless render over
 the committed chaos_partition golden stream, and the byte-identity
 contract: a golden scenario run with telemetry + tracing + runtime
 records enabled must still verify against its committed golden."""
+import glob
 import json
 import os
 import threading
 import time
+from collections import Counter
 
 import pytest
 
 from repro.obs.console import ConsoleState, render, sparkline
 from repro.obs.spans import (
-    NULL_TRACER, SpanTracer, validate_chrome_trace,
+    NULL_TRACER, PROGRAM_BUILD_EVENT, SpanTracer, program_build_listener,
+    validate_chrome_trace,
 )
 from repro.obs.tail import TailReader, read_complete_lines
 from repro.telemetry import (
@@ -280,6 +283,196 @@ def test_validate_chrome_trace_rejects_malformed():
 
 
 # ---------------------------------------------------------------------------
+# Spans inside the inner round, program builds, the profiler's clock
+# ---------------------------------------------------------------------------
+
+#: the spans of each of the H steps of a round
+PER_STEP = ("batch_sample", "batch_to_device", "inner_dispatch")
+IN_ROUND = PER_STEP + ("round_copy", "pseudo_gradient")
+
+
+def _round_job(compression="none", outer_steps=8):
+    """Two workers (paces 1 and 2), H = 2, one arrival per commit, a
+    one-layer model."""
+    import dataclasses
+    cfg = _tiny_cfg(commit_batch=1, outer_steps=outer_steps, inner_steps=2,
+                    compression=compression)
+    return dataclasses.replace(
+        cfg, model=dataclasses.replace(cfg.model, n_layers=1))
+
+
+def _traced_run(eng, capture=None):
+    """``eng.run()`` inside ``capture`` (a context manager), with the
+    lowerings during the run counted by a listener of the test's own."""
+    import contextlib
+
+    from jax import monitoring
+    lowered = []
+
+    def count(event, duration, **kw):
+        if event == PROGRAM_BUILD_EVENT:
+            lowered.append(kw["fun_name"])
+
+    with capture or contextlib.nullcontext():
+        monitoring.register_event_duration_secs_listener(count)
+        try:
+            eng.run()
+        finally:
+            monitoring.unregister_event_duration_listener(count)
+    return lowered
+
+
+def _spans(tr):
+    return [e for e in tr.to_chrome()["traceEvents"] if e.get("ph") == "X"]
+
+
+def _within(child, parent):
+    """``child`` lies inside ``parent`` on the same thread (trace us)."""
+    return (child["tid"] == parent["tid"] and child["ts"] >= parent["ts"]
+            and child["ts"] + child["dur"]
+            <= parent["ts"] + parent["dur"] + 1e-3)
+
+
+def _after_warmup(tr):
+    """The ``program_build`` and ``worker_round`` spans that start after
+    both workers have committed once."""
+    spans = _spans(tr)
+    warm = sorted(e["ts"] + e["dur"] for e in spans
+                  if e["name"] == "server_commit")[1]
+    return ([e for e in spans if e["name"] == "program_build"
+             and e["ts"] > warm],
+            [e for e in spans if e["name"] == "worker_round"
+             and e["ts"] > warm])
+
+
+@pytest.fixture(scope="module")
+def traced_fp32(tmp_path_factory):
+    """A tiny fp32 sim run with a ``SpanTracer``, inside a CPU profiler
+    capture: the engine, its tracer, the lowerings an independent
+    listener counted during ``run``, and the capture's directory."""
+    import jax
+
+    from repro.async_engine.engine import make_engine
+    tr = SpanTracer()
+    eng = make_engine(_round_job(), tracer=tr)
+    out = str(tmp_path_factory.mktemp("profile"))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    lowered = _traced_run(eng, jax.profiler.trace(out,
+                                                  profiler_options=opts))
+    return eng, tr, lowered, out
+
+
+def test_worker_round_nests_sampling_transfer_dispatch_copy_and_delta(
+        traced_fp32):
+    """Per ``worker_round``: H ``batch_sample``, ``batch_to_device`` and
+    ``inner_dispatch`` spans and one ``round_copy`` and
+    ``pseudo_gradient`` inside it; one ``round_dispatch`` per dispatch,
+    outside every round."""
+    eng, tr, _, _ = traced_fp32
+    spans = _spans(tr)
+    rounds = [e for e in spans if e["name"] == "worker_round"]
+    assert len(rounds) == eng.cfg.outer_steps
+    totals = Counter(e["name"] for e in spans)
+    for r in rounds:
+        h = r["args"]["h"]
+        assert h == eng.cfg.inner_steps
+        inside = Counter(e["name"] for e in spans
+                         if e is not r and _within(e, r))
+        assert {n: inside[n] for n in IN_ROUND} == {
+            **dict.fromkeys(PER_STEP, h), "round_copy": 1,
+            "pseudo_gradient": 1}
+    assert all(totals[n] == totals["worker_round"] * eng.cfg.inner_steps
+               for n in PER_STEP)
+    assert totals["round_copy"] == totals["pseudo_gradient"] == len(rounds)
+    dispatches = [e for e in spans if e["name"] == "round_dispatch"]
+    in_flight = sum(w.in_flight for w in eng.workers.values())
+    assert len(dispatches) == len(rounds) + in_flight
+    assert not any(_within(d, r) for d in dispatches for r in rounds)
+
+
+def test_profiler_capture_holds_the_round_spans_on_the_host_plane(
+        traced_fp32):
+    """The program's spans are ``TraceAnnotation``s of a ``jax.profiler``
+    capture: on ``/host:CPU``, one per span, each inside a round."""
+    from jax.profiler import ProfileData
+    _, tr, _, out = traced_fp32
+    files = sorted(glob.glob(os.path.join(out, "plugins", "profile", "*",
+                                          "*.xplane.pb")))
+    assert files, "the capture wrote no profile"
+    host = [(e.name, e.start_ns, e.end_ns)
+            for plane in ProfileData.from_file(files[-1]).planes
+            if plane.name == "/host:CPU"
+            for line in plane.lines for e in line.events]
+    got = Counter(name for name, _, _ in host)
+    want = Counter(e["name"] for e in _spans(tr))
+    for name in IN_ROUND + ("worker_round", "round_dispatch"):
+        assert got[name] == want[name] > 0, name
+    rounds = [(s, e) for name, s, e in host if name == "worker_round"]
+    for name, s, e in host:
+        if name in IN_ROUND:
+            assert any(a <= s and e <= b for a, b in rounds), name
+
+
+def test_program_builds_equal_the_lowerings_and_stop_after_warmup(
+        traced_fp32):
+    """An fp32 job lowers nothing once both workers have committed, and
+    every lowering during ``run`` is one ``program_build`` span."""
+    _, tr, lowered, _ = traced_fp32
+    builds = [e for e in _spans(tr) if e["name"] == "program_build"]
+    assert builds and sorted(e["args"]["fun"] for e in builds) \
+        == sorted(lowered)
+    late_builds, late_rounds = _after_warmup(tr)
+    assert late_rounds and late_builds == []
+
+
+def test_packed_int8_job_builds_programs_every_round():
+    """The packed int8 round trip lowers its kernels each round: more
+    than 0 ``program_build`` a round after warm-up, each a lowering."""
+    from repro.async_engine.engine import make_engine
+    tr = SpanTracer()
+    lowered = _traced_run(make_engine(_round_job("int8"), tracer=tr))
+    builds = [e for e in _spans(tr) if e["name"] == "program_build"]
+    assert sorted(e["args"]["fun"] for e in builds) == sorted(lowered)
+    late_builds, late_rounds = _after_warmup(tr)
+    assert late_rounds and len(late_builds) / len(late_rounds) > 0
+
+
+def test_build_listener_only_inside_a_traced_run(monkeypatch, traced_fp32):
+    """No listener is ever registered under ``NULL_TRACER``; a traced
+    run's listener is gone once ``run`` returns or raises."""
+    import jax
+    from jax import monitoring
+
+    from repro.async_engine.engine import make_engine
+    registered = []
+    register = monitoring.register_event_duration_secs_listener
+
+    def spy(callback):
+        registered.append(callback)
+        register(callback)
+
+    monkeypatch.setattr(monitoring, "register_event_duration_secs_listener",
+                        spy)
+    make_engine(_round_job(outer_steps=2)).run()
+    assert registered == []
+
+    tr = SpanTracer()
+    eng = make_engine(_round_job(outer_steps=2), tracer=tr)
+
+    def failing_eval(params, step, time):
+        raise RuntimeError("eval failed")
+
+    with pytest.raises(RuntimeError, match="eval failed"):
+        eng.run(eval_every=1, eval_fn=failing_eval)
+    assert len(registered) == 1
+    done = [traced_fp32[1], tr]
+    before = [len(t) for t in done]
+    jax.jit(lambda x: x * 3.0 + 1.0)(2.0)        # a fresh lowering
+    assert [len(t) for t in done] == before
+
+
+# ---------------------------------------------------------------------------
 # Operator console (headless) over the committed golden stream
 # ---------------------------------------------------------------------------
 
@@ -340,8 +533,17 @@ def test_trace_cli_validate(tmp_path, capsys):
     tr = SpanTracer()
     with tr.span("s"):
         pass
+    builds = program_build_listener(tr)
+    for fun in ("jit(step)", "jit(wrapped)", "jit(wrapped)"):
+        builds(PROGRAM_BUILD_EVENT, 0.01, fun_name=fun)
+    builds("/jax/core/compile/backend_compile_duration", 0.5, fun_name="x")
     p = tr.write(str(tmp_path / "t.json"))
+    capsys.readouterr()
     assert obs_main(["trace", p, "--validate"]) == 0
+    out = capsys.readouterr().out
+    assert "program builds by function (3)" in out
+    assert [ln.split() for ln in out.splitlines() if "jit(" in ln] == [
+        ["jit(wrapped)", "x2"], ["jit(step)", "x1"]]
     bad = tmp_path / "bad.json"
     bad.write_text('{"traceEvents": [{"ph": "X"}]}')
     capsys.readouterr()
@@ -498,17 +700,19 @@ def test_web_server_routes_live(tmp_path):
 # Commit-buffer flush telemetry (schema v4 "flush" records)
 # ---------------------------------------------------------------------------
 
-def _tiny_cfg(commit_batch=2, outer_steps=6):
+def _tiny_cfg(commit_batch=2, outer_steps=6, inner_steps=1,
+              compression="none"):
     import dataclasses
 
     from repro.configs import get_config, reduced
     from repro.configs.base import InnerOptConfig, OuterOptConfig, RunConfig
     cfg = reduced(get_config("tinygpt-15m"))
     return dataclasses.replace(RunConfig(
-        model=cfg, n_workers=2, inner_steps=1, outer_steps=outer_steps,
+        model=cfg, n_workers=2, inner_steps=inner_steps,
+        outer_steps=outer_steps,
         batch_size=2, seq_len=16, worker_paces=(1.0, 2.0), non_iid=True,
         inner=InnerOptConfig(lr=3e-3, warmup_steps=2, total_steps=100),
-        outer=OuterOptConfig(method="heloco")),
+        outer=OuterOptConfig(method="heloco", compression=compression)),
         commit_batch=commit_batch)
 
 
