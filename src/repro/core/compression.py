@@ -11,8 +11,11 @@ Two int8 paths:
     ``repro.core.packing.BlockLayout`` and quantized per BLOCK (same
     granularity, finer for stacked-layer leaves) with O(1) kernel launches
     per round-trip instead of O(#leaves); the error-feedback buffer also
-    lives packed, so the whole worker-side compression step is three flat
-    sweeps (absmax, quantize, dequantize) over one (R, 128) buffer.
+    lives packed. The whole worker-side step (pack, ``+ ef``, the three
+    flat sweeps absmax / quantize / dequantize over one (R, 128) buffer,
+    the per-block scales and the residual) is ONE compiled program,
+    traced once per layout (and once more for the first round's
+    ``ef=None``), so a round dispatches it and lowers nothing.
 """
 from __future__ import annotations
 
@@ -20,6 +23,10 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from repro.core import packing
+from repro.kernels import packed as pk
+from repro.kernels.ops import _auto_interpret
 
 PyTree = Any
 
@@ -87,32 +94,56 @@ def compressed_bytes(c: Compressed) -> int:
     return sum(x.size * x.dtype.itemsize for x in vals)
 
 
-def packed_int8_roundtrip(buf: jnp.ndarray, layout,
-                          interpret: bool | None = None
-                          ) -> Tuple[jnp.ndarray, int]:
-    """Per-block int8 fake-quantization of a packed (R, 128) buffer.
-
-    One absmax sweep + an O(R) segment-max gives per-block scales; one
-    quantize and one dequantize sweep complete the round-trip — 3 kernel
-    launches total regardless of #blocks. Returns (decoded_buf, wire_bytes)
-    where wire_bytes counts only real elements (int8) + one fp32 scale per
-    block, matching the per-leaf accounting.
-    """
-    from repro.kernels import packed as pk
-    from repro.kernels.ops import _auto_interpret
-
-    interpret = _auto_interpret(interpret)
-    row_block = jnp.asarray(layout.row_block)
-    rowabs = pk.packed_rowabs(buf, interpret=interpret)[:, 0]
+def _packed_int8_program(delta: PyTree, ef: Optional[jnp.ndarray], layout,
+                         interpret: bool, rows: int | None
+                         ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    dbuf = packing.pack(layout, delta)
+    target = dbuf if ef is None else dbuf + ef
+    rowabs = pk.packed_rowabs(target, interpret=interpret, rows=rows)[:, 0]
     # blocks are contiguous row spans: static slices beat a segment max
     blockabs = jnp.stack([rowabs[s:e].max()
                           for s, e in layout.block_row_ranges])
-    scale = jnp.maximum(blockabs, 1e-12) / 127.0
-    scale_rows = scale[row_block][:, None]
-    q = pk.packed_quant(buf, scale_rows, interpret=interpret)
-    decoded = pk.packed_dequant(q, scale_rows, interpret=interpret)
+    # a true division, as the eager op computed it: XLA turns a division
+    # by a constant into a multiply by its (rounded) reciprocal
+    scale = (jnp.maximum(blockabs, 1e-12)
+             / jax.lax.optimization_barrier(jnp.float32(127.0)))
+    scale_rows = scale[layout.row_block][:, None]
+    q = pk.packed_quant(target, scale_rows, interpret=interpret, rows=rows)
+    decoded = pk.packed_dequant(q, scale_rows, interpret=interpret,
+                                rows=rows)
+    return decoded, target - decoded
+
+
+_STATIC = ("layout", "interpret", "rows")
+_compiled_program = jax.jit(_packed_int8_program, static_argnames=_STATIC)
+# The interpreter inlines the kernels into the program, where XLA:CPU's
+# fusion would contract the dequantize multiply into the residual's
+# subtract (an FMA). Unfused, every op rounds as the eager ops do.
+_interpreted_program = jax.jit(
+    _packed_int8_program, static_argnames=_STATIC,
+    compiler_options={"xla_disable_hlo_passes": "fusion"})
+
+
+def packed_int8_roundtrip(delta: PyTree, ef: Optional[jnp.ndarray], layout,
+                          interpret: bool | None = None,
+                          rows: int | None = None
+                          ) -> Tuple[jnp.ndarray, jnp.ndarray, int]:
+    """Per-block int8 fake-quantization of ``pack(layout, delta) + ef``.
+
+    One absmax sweep + an O(R) per-block max gives per-block scales; one
+    quantize and one dequantize sweep complete the round-trip — 3 kernel
+    launches total regardless of #blocks, all inside one jitted program
+    keyed on the (hashable) layout and on whether ``ef`` is None.
+    Returns (decoded_buf, new_ef, wire_bytes): new_ef = target - decoded
+    in fp32, and wire_bytes counts only real elements (int8) + one fp32
+    scale per block, matching the per-leaf accounting. ``ef`` is not
+    donated. rows: kernel row-tile override (tests: multi-step grids).
+    """
+    interpret = _auto_interpret(interpret)
+    program = _interpreted_program if interpret else _compiled_program
+    decoded, new_ef = program(delta, ef, layout, interpret, rows)
     nbytes = int(layout.total_elems) + 4 * layout.n_blocks
-    return decoded, nbytes
+    return decoded, new_ef, nbytes
 
 
 def roundtrip_with_error_feedback(delta: PyTree, ef: Optional[PyTree],
@@ -132,12 +163,7 @@ def roundtrip_with_error_feedback(delta: PyTree, ef: Optional[PyTree],
     synchronizer consumes it without an unpack -> re-pack detour.
     """
     if kind == "int8" and layout is not None:
-        from repro.core import packing
-
-        dbuf = packing.pack(layout, delta)
-        target = dbuf if ef is None else dbuf + ef
-        decoded_buf, nbytes = packed_int8_roundtrip(target, layout)
-        new_ef = target - decoded_buf
+        decoded_buf, new_ef, nbytes = packed_int8_roundtrip(delta, ef, layout)
         return packing.Packed(decoded_buf), new_ef, nbytes
     if kind == "none":
         zeros = jax.tree.map(lambda x: jnp.zeros_like(x, jnp.float32), delta)

@@ -22,6 +22,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.configs.base import InnerOptConfig
 from repro.core import packing
+from repro.core.compression import _compiled_program
 from repro.kernels import packed as pk
 from repro.kernels.tiling import LANES
 from repro.models import build_model
@@ -135,6 +136,19 @@ def test_int8_roundtrip_kernels_compile(one_chip, layout):
     _compile(functools.partial(pk.packed_quant, interpret=False), buf, col)
     _compile(functools.partial(pk.packed_dequant, interpret=False),
              _on(one_chip, (r, LANES), jnp.int8), col)
+
+
+@pytest.mark.parametrize("carried_ef", [False, True])
+def test_int8_roundtrip_program_compiles(one_chip, layout, param_shapes,
+                                         carried_ef):
+    """The worker's whole packed int8 round trip is one program: its
+    three Mosaic kernels, and the scale's true division kept."""
+    delta = jax.tree.map(lambda s: _on(one_chip, s.shape), param_shapes)
+    ef = _on(one_chip, (layout.n_rows, LANES)) if carried_ef else None
+    text = _compiled_program.lower(delta, ef, layout, False,
+                                   None).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert " divide(" in text
 
 
 def test_inner_step_compiles(one_chip, model, param_shapes):

@@ -427,15 +427,20 @@ def test_program_builds_equal_the_lowerings_and_stop_after_warmup(
 
 
 def test_packed_int8_job_builds_programs_every_round():
-    """The packed int8 round trip lowers its kernels each round: more
-    than 0 ``program_build`` a round after warm-up, each a lowering."""
+    """The packed int8 round trip is one program, lowered twice in a run
+    at most (a worker's first round carries no error feedback, its later
+    rounds do) and not every round: after warm-up at most its second
+    trace remains, each ``program_build`` a lowering."""
     from repro.async_engine.engine import make_engine
     tr = SpanTracer()
     lowered = _traced_run(make_engine(_round_job("int8"), tracer=tr))
     builds = [e for e in _spans(tr) if e["name"] == "program_build"]
     assert sorted(e["args"]["fun"] for e in builds) == sorted(lowered)
+    assert lowered.count("jit(_packed_int8_program)") <= 2  # 0: cached
     late_builds, late_rounds = _after_warmup(tr)
-    assert late_rounds and len(late_builds) / len(late_rounds) > 0
+    assert len(late_rounds) > 2
+    assert [e["args"]["fun"] for e in late_builds] in (
+        [], ["jit(_packed_int8_program)"])
 
 
 def test_build_listener_only_inside_a_traced_run(monkeypatch, traced_fp32):

@@ -8,7 +8,9 @@ import pytest
 
 from repro.configs.base import HeLoCoConfig, OuterOptConfig
 from repro.core import packing
-from repro.core.compression import roundtrip_with_error_feedback
+from repro.core.compression import (
+    packed_int8_roundtrip, roundtrip_with_error_feedback,
+)
 from repro.core.heloco import (
     apply_arrival, apply_arrival_packed, init_outer_state,
     momentum_decay_update,
@@ -17,6 +19,7 @@ from repro.async_engine.server import Synchronizer
 from repro.kernels import ops
 from repro.kernels import packed as pk
 from repro.kernels.tiling import LANES, ROW_ALIGN, ROWS, padded_rows, row_tile
+from repro.obs.spans import SpanTracer, program_build_listener
 
 H = HeLoCoConfig()
 
@@ -309,3 +312,80 @@ def test_packed_int8_stacked_scales_per_block():
     # layer 2 survives with its own scale (per-leaf scale 1000/127 would
     # round 0.001 to zero)
     np.testing.assert_allclose(np.asarray(dec["w"][2]), 0.001, rtol=0.01)
+
+
+def _int8_tree(key, extra=5 * LANES):
+    """``_tree`` plus one leaf of ``extra`` elements: with the default the
+    layout has 24 rows (three 8-row grid steps) in 10 blocks."""
+    return {**_tree(key), "extra": jax.random.normal(
+        jax.random.fold_in(key, 7), (extra,))}
+
+
+_INT8_STACKED = {**STACKED, "extra": 0}
+
+
+def _eager_int8_roundtrip(layout, delta, ef, rows):
+    """The packed int8 round trip as eager ops, one dispatch each."""
+    dbuf = packing.pack(layout, delta)
+    target = dbuf if ef is None else dbuf + ef
+    rowabs = pk.packed_rowabs(target, interpret=True, rows=rows)[:, 0]
+    blockabs = jnp.stack([rowabs[s:e].max()
+                          for s, e in layout.block_row_ranges])
+    scale = jnp.maximum(blockabs, 1e-12) / 127.0
+    scale_rows = scale[jnp.asarray(layout.row_block)][:, None]
+    q = pk.packed_quant(target, scale_rows, interpret=True, rows=rows)
+    decoded = pk.packed_dequant(q, scale_rows, interpret=True, rows=rows)
+    return decoded, target - decoded
+
+
+@pytest.mark.parametrize("rows", [None, 8])
+@pytest.mark.parametrize("carried_ef", [False, True])
+def test_packed_int8_program_equals_eager_ops(carried_ef, rows):
+    """The jitted round trip gives the eager composition's decoded buffer
+    and error feedback bit for bit: with and without a carried error,
+    stacked-layer blocks, one grid step or three."""
+    key = jax.random.PRNGKey(11)
+    delta = _int8_tree(key)
+    delta["layers"]["w"] = delta["layers"]["w"] * 100.0   # per-block scales
+    layout = packing.build_layout(delta, _INT8_STACKED)
+    assert layout.n_rows == 24 and layout.n_blocks == 10
+    ef = None
+    if carried_ef:
+        ef = 0.01 * packing.pack(layout, _int8_tree(jax.random.PRNGKey(12)))
+    decoded, new_ef, nbytes = packed_int8_roundtrip(
+        delta, ef, layout, interpret=True, rows=rows)
+    want_decoded, want_ef = _eager_int8_roundtrip(layout, delta, ef, rows)
+    np.testing.assert_array_equal(np.asarray(decoded),
+                                  np.asarray(want_decoded))
+    np.testing.assert_array_equal(np.asarray(new_ef), np.asarray(want_ef))
+    assert nbytes == layout.total_elems + 4 * layout.n_blocks
+
+
+def test_packed_int8_program_builds_once_per_layout():
+    """Repeated round trips with one layout lower nothing after the first;
+    a second layout lowers its program once."""
+    def builds(calls):
+        tr = SpanTracer()
+        listen = program_build_listener(tr)
+        jax.monitoring.register_event_duration_secs_listener(listen)
+        try:
+            for delta, ef, layout in calls:
+                jax.block_until_ready(packed_int8_roundtrip(delta, ef,
+                                                            layout)[:2])
+        finally:
+            jax.monitoring.unregister_event_duration_listener(listen)
+        return [e for e in tr.to_chrome()["traceEvents"]
+                if e.get("name") == "program_build"]
+
+    delta = _int8_tree(jax.random.PRNGKey(0))
+    layout = packing.build_layout(delta, _INT8_STACKED)
+    _, ef, _ = packed_int8_roundtrip(delta, None, layout)
+    builds([(delta, ef, layout)])                       # first: may lower
+    again = [(jax.tree.map(lambda x: x * (i + 2.0), delta), ef, layout)
+             for i in range(2)]
+    assert builds(again) == []
+    # a leaf size no other test uses: a layout this process has not seen
+    other = _int8_tree(jax.random.PRNGKey(1), extra=3 * LANES + 1)
+    other_layout = packing.build_layout(other, _INT8_STACKED)
+    new = builds([(other, None, other_layout)])
+    assert [e["args"]["fun"] for e in new] == ["jit(_packed_int8_program)"]
