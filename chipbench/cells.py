@@ -1,12 +1,15 @@
 """A benchmark cell, found by name: its entry in ``BENCHMARK.json``, its
-model configuration (``configs/<config>.json``), its training job
-(``traffic/<traffic>.json``), the limits of its correctness check
-(``limits/<cell>.json``) and the metrics it reports."""
+model configuration (``configs/<config>.json``) and the architecture
+module that configuration names (``arch/<architecture>.py``), its
+training job (``traffic/<traffic>.json``), the limits of its correctness
+check (``limits/<cell>.json``) and the metrics it reports."""
 from __future__ import annotations
 
 import dataclasses
+import importlib.util
 import json
 import os
+import types
 from typing import Dict, List
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -18,6 +21,7 @@ class Cell:
     name: str
     chips: int
     config: dict            # the configuration file (model fields, source)
+    arch: types.ModuleType  # its architecture: weights, loss, FLOP count
     job: dict               # the traffic file: the training job
     limits: Dict[str, float]
     end_to_end: List[dict]  # metric entries of BENCHMARK.json it reports
@@ -31,6 +35,30 @@ class Cell:
 def _read(path: str) -> dict:
     with open(path) as f:
         return json.load(f)
+
+
+#: what the reference and the counts take from an architecture module
+ARCH_FUNCTIONS = ("init_params", "make_loss", "train_flops_per_token")
+
+
+def load_arch(name, root: str = ROOT) -> types.ModuleType:
+    """The architecture module ``chipbench/arch/<name>.py`` under
+    ``root``; an unknown name is an error that lists the known ones."""
+    here = os.path.join(root, "chipbench", "arch")
+    known = sorted(f[:-3] for f in os.listdir(here) if f.endswith(".py"))
+    if name not in known:
+        raise KeyError(f"no architecture {name!r} in chipbench/arch; "
+                       f"known: {known}")
+    spec = importlib.util.spec_from_file_location(
+        "chipbench_arch_" + name.replace("-", "_").replace(".", "_"),
+        os.path.join(here, name + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    missing = [f for f in ARCH_FUNCTIONS if not callable(getattr(mod, f,
+                                                                 None))]
+    if missing:
+        raise TypeError(f"architecture {name!r} lacks {missing}")
+    return mod
 
 
 def load_cell(name: str, root: str = ROOT) -> Cell:
@@ -51,9 +79,10 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
     moved = {m["name"] for m in end_to_end}
     per_layer = [m for m in bench["per_layer"]
                  if m["moves"] in moved and name in m.get("workloads", [name])]
+    config = _read(os.path.join(root, configs[w["config"]]["file"]))
     return Cell(
-        name=name, chips=int(w["chips"]),
-        config=_read(os.path.join(root, configs[w["config"]]["file"])),
+        name=name, chips=int(w["chips"]), config=config,
+        arch=load_arch(config.get("architecture"), root),
         job=_read(os.path.join(here, "traffic", w["traffic"] + ".json")),
         limits=_read(os.path.join(here, "limits", name + ".json")),
         end_to_end=end_to_end, per_layer=per_layer)

@@ -1,56 +1,34 @@
-"""Operations and bytes the trainer's work needs, from the configuration's
-shapes alone (no code of the program)."""
+"""Bytes the trainer's work needs, from the configuration's shapes alone
+(no code of the program): the shapes of the architecture module's
+weights (``chipbench/arch/<kind>.py``), whose ``train_flops_per_token``
+counts the operations."""
 from __future__ import annotations
 
 import math
 from typing import List
+
+import jax
 
 LANES = 128                  # floats per row of the packed commit buffer
 ROW_ALIGN = 8                # rows: the buffer is padded to a whole tile
 F32 = 4
 
 
-def leaf_sizes(model: dict) -> List[int]:
-    """Element count of every parameter tensor of the dense block stack
-    with tied embeddings: token table, final LayerNorm, and per layer two
-    LayerNorms, q/k/v/o projections and the two MLP matrices, with the
-    optional q/k/v and MLP biases."""
-    d, h, kv, hd, ff = (model["d_model"], model["n_heads"],
-                        model["n_kv_heads"], model["head_dim"], model["d_ff"])
-    per_layer = [d, d, d * h * hd, d * kv * hd, d * kv * hd, h * hd * d,
-                 d, d, d * ff, ff * d]
-    if model.get("qkv_bias"):
-        per_layer += [h * hd, kv * hd, kv * hd]
-    if model.get("mlp_bias"):
-        per_layer += [ff, d]
-    return [model["vocab_size"] * d, d, d] + per_layer * model["n_layers"]
+def leaf_sizes(arch, model: dict) -> List[int]:
+    """Element count of every parameter tensor: the leaves of the
+    architecture's weights, by shape alone."""
+    shapes = jax.eval_shape(lambda: arch.init_params(model, 0))
+    return [math.prod(x.shape) for x in jax.tree.leaves(shapes)]
 
 
-def n_params(model: dict) -> int:
-    return sum(leaf_sizes(model))
+def n_params(arch, model: dict) -> int:
+    return sum(leaf_sizes(arch, model))
 
 
-def matmul_params(model: dict) -> int:
-    """Weights that enter a matrix product, the tied output head once."""
-    d, h, kv, hd, ff = (model["d_model"], model["n_heads"],
-                        model["n_kv_heads"], model["head_dim"], model["d_ff"])
-    layer = d * h * hd * 2 + d * kv * hd * 2 + 2 * d * ff
-    return model["n_layers"] * layer + model["vocab_size"] * d
-
-
-def train_flops_per_token(model: dict, seq: int) -> float:
-    """Forward and backward matmul operations per trained token: 6 per
-    matmul weight, plus 12 * layers * heads * head_dim * seq for the
-    attention scores and their weighted sum (PaLM's model-FLOPs count;
-    no recomputation)."""
-    attn = 12 * model["n_layers"] * model["n_heads"] * model["head_dim"] * seq
-    return 6.0 * matmul_params(model) + attn
-
-
-def packed_rows(model: dict) -> int:
+def packed_rows(arch, model: dict) -> int:
     """Rows R of the (R, 128) commit buffer: each tensor padded to whole
     rows, the total padded to a whole row tile."""
-    rows = sum(math.ceil(n / LANES) for n in leaf_sizes(model))
+    rows = sum(math.ceil(n / LANES) for n in leaf_sizes(arch, model))
     return -(-rows // ROW_ALIGN) * ROW_ALIGN
 
 
@@ -65,4 +43,3 @@ def commit_bytes(rows: int, k: int) -> int:
     if k == 1:
         return 7 * rows * row
     return (2 * k + 5) * rows * row
-

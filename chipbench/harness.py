@@ -176,12 +176,37 @@ def _marker(name: str):
 HARNESS_KEYS = ("about", "engine", "runtime", "eval_batch", "eval_every")
 
 
+def _tuples(value):
+    """JSON lists as tuples, inside dicts too, so that a frozen
+    configuration hashes."""
+    if isinstance(value, list):
+        return tuple(_tuples(v) for v in value)
+    if isinstance(value, dict):
+        return {k: _tuples(v) for k, v in value.items()}
+    return value
+
+
+def model_config(model: dict):
+    """The program's ``ModelConfig`` for a configuration's ``model``:
+    lists become tuples, and the nested ``moe``, ``ssm``, ``xlstm`` and
+    ``frontend`` groups their configuration classes."""
+    from repro.configs.base import (FrontendConfig, ModelConfig, MoEConfig,
+                                    SSMConfig, XLSTMConfig)
+    groups = {"moe": MoEConfig, "ssm": SSMConfig, "xlstm": XLSTMConfig,
+              "frontend": FrontendConfig}
+    fields = _tuples(model)
+    for key, cls in groups.items():
+        if key in fields:
+            fields[key] = cls(**fields[key])
+    return ModelConfig(**fields)
+
+
 def run_config(cell: Cell, seed: int):
     """The program's ``RunConfig`` for this cell's job: every key of the
     traffic file but the harness's own, with the model of the cell's
     configuration; the job runs until the window closes."""
     from repro.configs.base import (HeLoCoConfig, InnerOptConfig,
-                                    ModelConfig, OuterOptConfig, RunConfig)
+                                    OuterOptConfig, RunConfig)
     fields = {k: v for k, v in cell.job.items() if k not in HARNESS_KEYS}
     outer = dict(fields["outer"])
     outer["heloco"] = HeLoCoConfig(**outer["heloco"])
@@ -189,7 +214,7 @@ def run_config(cell: Cell, seed: int):
                   outer=OuterOptConfig(**outer),
                   worker_paces=tuple(float(p)
                                      for p in fields["worker_paces"]))
-    return RunConfig(model=ModelConfig(**cell.model), seed=seed,
+    return RunConfig(model=model_config(cell.model), seed=seed,
                      outer_steps=10 ** 9, **fields)
 
 
@@ -249,7 +274,8 @@ def worst_leaves(prog: dict, ref: dict) -> Dict[str, str]:
 
 
 def reference_readings(cell: Cell, seed: int, **kw) -> dict:
-    return Reference(cell.model, cell.job, seed, **kw).run(CHECK_STEPS)
+    return Reference(cell.arch, cell.model, cell.job, seed,
+                     **kw).run(CHECK_STEPS)
 
 
 def quantity(metric: str) -> str:

@@ -9,6 +9,6 @@ def read(run):
     commits = run.arrivals()
     if not n or not commits:
         return None
-    rows = flops.packed_rows(run.cell.model)
+    rows = flops.packed_rows(run.cell.arch, run.cell.model)
     need = sum(flops.commit_bytes(rows, k) for k in commits)
     return 100.0 * need / (seconds * run.peaks["hbm_bytes_per_s"])

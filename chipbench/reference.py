@@ -1,25 +1,23 @@
 """Plain reference of the first steps of an async HeLoCo training job.
 
 Written from the published equations and the job's data files, with
-nothing imported from the program under test: the transformer block
-(LayerNorm, rotary attention, tanh-GELU MLP, tied embeddings), its loss
-and gradient, AdamW with global-norm clipping and warm-up/cosine
-schedule, the pseudo-gradient, the per-tensor int8 round trip with error
-feedback, the HeLoCo per-block correction with the outer Nesterov step,
-the momentum look-ahead worker start, the synthetic per-language corpus
-and the virtual-clock arrival order. Weights come from the seed by the
-same key derivation the configuration's initialisation states.
+nothing imported from the program under test: AdamW with global-norm
+clipping and warm-up/cosine schedule, the pseudo-gradient, the
+per-tensor int8 round trip with error feedback, the HeLoCo per-block
+correction with the outer Nesterov step, the momentum look-ahead worker
+start, the synthetic per-language corpus and the virtual-clock arrival
+order. The model (its weights from the seed, by the key derivation the
+configuration's initialisation states, and its loss) comes from the
+configuration's architecture module, ``chipbench/arch/<kind>.py``.
 
-``matmul`` names the precision of the model's matrix products: "bf16"
-follows the configuration (bfloat16 operands, float32 accumulation);
-"fp8" rounds every operand of the forward products to float8 e4m3 with
-a per-tensor scale first, the lower precision that serves as the
-control. ``fault`` plants one of the faults a training step can have:
-"half_batch" takes the loss over the first half of each batch only,
-"state_unchanged" makes every commit leave the server's state as it
-was, "delta_altered" doubles the first tensor of every pseudo-gradient
-where it is made. Every other computation runs in float32 at full
-matmul precision.
+``matmul`` names the precision of the model's matrix products
+(``chipbench/precision.py``): "bf16" follows the configuration, "fp8" is
+the lower precision that serves as the control. ``fault`` plants one of
+the faults a training step can have: "half_batch" takes the loss over
+the first half of each batch only, "state_unchanged" makes every commit
+leave the server's state as it was, "delta_altered" doubles the first
+tensor of every pseudo-gradient where it is made. Every other
+computation runs in float32 at full matmul precision.
 """
 from __future__ import annotations
 
@@ -32,7 +30,6 @@ import jax.numpy as jnp
 import numpy as np
 
 LANGS = ("de", "en", "es", "fr", "it")
-F8_MAX = 448.0                       # largest finite float8 e4m3fn
 
 
 # ---------------------------------------------------------------------------
@@ -123,131 +120,6 @@ def scheduled_steps(paces: Sequence[float], n_workers: int, h: int,
 
 
 # ---------------------------------------------------------------------------
-# Model
-# ---------------------------------------------------------------------------
-
-def _normal(key, shape, scale):
-    return scale * jax.random.normal(key, shape, jnp.float32)
-
-
-def init_params(cfg: dict, seed: int) -> dict:
-    """Weights from the seed: N(0, 0.02) token table, N(0, 1/fan_in)
-    projections, unit LayerNorm scales, zero biases; one key per layer
-    split from the block key, in the configuration's key order."""
-    d, h, kv, hd, ff = (cfg["d_model"], cfg["n_heads"], cfg["n_kv_heads"],
-                        cfg["head_dim"], cfg["d_ff"])
-    k_embed, k_blocks, _ = jax.random.split(jax.random.PRNGKey(seed), 3)
-    ln = lambda: {"bias": jnp.zeros((d,)), "scale": jnp.ones((d,))}
-    params = {"embed": {"tok": _normal(jax.random.split(k_embed, 2)[0],
-                                       (cfg["vocab_size"], d), 0.02)},
-              "final_norm": ln(), "blocks_list": {}}
-    for i, key in enumerate(jax.random.split(k_blocks, cfg["n_layers"])):
-        k_attn, _, k_mlp = jax.random.split(key, 3)
-        ka = jax.random.split(k_attn, 4)
-        attn = {"wq": _normal(ka[0], (d, h, hd), d ** -0.5),
-                "wk": _normal(ka[1], (d, kv, hd), d ** -0.5),
-                "wv": _normal(ka[2], (d, kv, hd), d ** -0.5),
-                "wo": _normal(ka[3], (h, hd, d), (h * hd) ** -0.5)}
-        if cfg.get("qkv_bias"):
-            attn.update(bq=jnp.zeros((h, hd)), bk=jnp.zeros((kv, hd)),
-                        bv=jnp.zeros((kv, hd)))
-        km = jax.random.split(k_mlp, 3)
-        mlp = {"w_in": _normal(km[0], (d, ff), d ** -0.5),
-               "w_down": _normal(km[2], (ff, d), ff ** -0.5)}
-        if cfg.get("mlp_bias"):
-            mlp.update(b_in=jnp.zeros((ff,)), b_down=jnp.zeros((d,)))
-        params["blocks_list"][f"layer_{i:02d}"] = {
-            "norm1": ln(), "attn": attn, "norm2": ln(), "mlp": mlp}
-    return params
-
-
-def _f8(x):
-    """Round to float8 e4m3 with one scale for the whole tensor; the
-    gradient passes through unrounded."""
-    xf = x.astype(jnp.float32)
-    s = jnp.maximum(jnp.max(jnp.abs(xf)), 1e-30) / F8_MAX
-    q = (xf / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
-    return (xf + jax.lax.stop_gradient(q - xf)).astype(jnp.bfloat16)
-
-
-def make_loss(cfg: dict, matmul: str = "bf16", half_batch: bool = False):
-    """loss(params, tokens, labels): mean next-token cross-entropy."""
-    bf = jnp.bfloat16
-    q8 = _f8 if matmul == "fp8" else (lambda x: x)
-    eps = cfg.get("norm_eps", 1e-5)
-    theta = cfg.get("rope_theta", 10000.0)
-
-    def mm(spec, a, b):
-        """bfloat16 operands, float32 accumulation, bfloat16 result."""
-        return jnp.einsum(spec, q8(a.astype(bf)), q8(b.astype(bf)),
-                          preferred_element_type=jnp.float32).astype(bf)
-
-    def norm(p, x):
-        xf = x.astype(jnp.float32)
-        mu = xf.mean(-1, keepdims=True)
-        var = ((xf - mu) ** 2).mean(-1, keepdims=True)
-        return ((xf - mu) * jax.lax.rsqrt(var + eps) * p["scale"]
-                + p["bias"]).astype(bf)
-
-    def rope(x):
-        dh = x.shape[-1]
-        freqs = 1.0 / (theta ** (jnp.arange(0, dh, 2, dtype=jnp.float32)
-                                 / dh))
-        ang = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * freqs
-        cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-        x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-        return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
-                               axis=-1).astype(bf)
-
-    def attention(p, x):
-        q = mm("bsd,dhk->bshk", x, p["wq"])
-        k = mm("bsd,dhk->bshk", x, p["wk"])
-        v = mm("bsd,dhk->bshk", x, p["wv"])
-        if "bq" in p:
-            q, k, v = q + p["bq"].astype(bf), k + p["bk"].astype(bf), \
-                v + p["bv"].astype(bf)
-        q, k = rope(q), rope(k)
-        g = q.shape[2] // k.shape[2]
-        k, v = jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2)
-        s = jnp.einsum("bqhk,bshk->bhqs", q8(q), q8(k),
-                       preferred_element_type=jnp.float32)
-        s = s * q.shape[-1] ** -0.5
-        n = s.shape[-1]
-        s = jnp.where(jnp.tril(jnp.ones((n, n), bool)), s, -1e30)
-        probs = jax.nn.softmax(s, axis=-1).astype(bf)
-        ctx = jnp.einsum("bhqs,bshk->bqhk", q8(probs), q8(v),
-                         preferred_element_type=jnp.float32).astype(bf)
-        return mm("bshk,hkd->bsd", ctx, p["wo"])
-
-    def mlp(p, x):
-        hdn = mm("bsd,df->bsf", x, p["w_in"])
-        if "b_in" in p:
-            hdn = hdn + p["b_in"].astype(bf)
-        out = mm("bsf,fd->bsd", jax.nn.gelu(hdn), p["w_down"])
-        if "b_down" in p:
-            out = out + p["b_down"].astype(bf)
-        return out
-
-    def loss(params, tokens, labels):
-        if half_batch:
-            tokens, labels = tokens[: len(tokens) // 2], labels[
-                : len(labels) // 2]
-        x = params["embed"]["tok"].astype(bf)[tokens]
-        for name in sorted(params["blocks_list"]):
-            lp = params["blocks_list"][name]
-            x = x + attention(lp["attn"], norm(lp["norm1"], x))
-            x = x + mlp(lp["mlp"], norm(lp["norm2"], x))
-        x = norm(params["final_norm"], x)
-        logits = mm("bsd,vd->bsv", x, params["embed"]["tok"]).astype(
-            jnp.float32)
-        nll = (jax.nn.logsumexp(logits, axis=-1)
-               - jnp.take_along_axis(logits, labels[..., None], -1)[..., 0])
-        return nll.mean()
-
-    return loss
-
-
-# ---------------------------------------------------------------------------
 # Optimisers
 # ---------------------------------------------------------------------------
 
@@ -321,7 +193,9 @@ def leaf_norms(tree) -> np.ndarray:
 
 
 class Reference:
-    """Follows a job from the seed through its first commits."""
+    """Follows a job from the seed through its first commits, with the
+    model of ``arch``, an architecture module (``chipbench/arch/``), at
+    the configuration ``model_cfg``."""
 
     FAULTS = ("", "half_batch", "state_unchanged", "delta_altered")
     #: fields of the job the reference follows only at these values
@@ -329,7 +203,7 @@ class Reference:
              "shard_assignment": "fixed", "topology": "hub",
              "batch_rampup": None, "grad_accum": 1}
 
-    def __init__(self, model_cfg: dict, job: dict, seed: int, *,
+    def __init__(self, arch, model_cfg: dict, job: dict, seed: int, *,
                  matmul: str = "bf16", fault: str = ""):
         if fault not in self.FAULTS:
             raise ValueError(f"unknown fault {fault!r}")
@@ -342,14 +216,15 @@ class Reference:
             if job.get(key, value) != value:
                 raise ValueError(f"the reference follows {key}={value!r} "
                                  f"only, not {job[key]!r}")
-        self.cfg, self.job, self.seed, self.fault = model_cfg, job, seed, fault
+        self.arch, self.cfg, self.job, self.seed, self.fault = \
+            arch, model_cfg, job, seed, fault
         self.n = job["n_workers"]
         self.specs = language_specs(model_cfg["vocab_size"],
                                     max(self.n, 2), seed)
         self.eval_set = eval_set(self.specs, job["eval_batch"],
                                  job["seq_len"], seed + 4242)
-        loss = make_loss(model_cfg, matmul, fault == "half_batch")
-        eval_loss = make_loss(model_cfg, matmul)
+        loss = arch.make_loss(model_cfg, matmul, fault == "half_batch")
+        eval_loss = arch.make_loss(model_cfg, matmul, False)
         inner = job["inner"]
 
         def train_step(params, opt, tokens, labels):
@@ -385,7 +260,7 @@ class Reference:
         per-leaf norms of the loss's gradient at the first inner step."""
         job, o = self.job, self.job["outer"]
         with jax.default_matmul_precision("highest"):
-            params = init_params(self.cfg, self.seed)
+            params = self.arch.init_params(self.cfg, self.seed)
             p0 = jax.tree.map(np.asarray, params)
             mom = jax.tree.map(jnp.zeros_like, params)
             zeros = lambda: jax.tree.map(jnp.zeros_like, params)

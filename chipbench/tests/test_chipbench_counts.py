@@ -8,37 +8,43 @@ import jax
 import pytest
 
 from chipbench import flops
-from chipbench.cells import HERE
+from chipbench.cells import HERE, load_arch
+
+#: rows R of each configuration's packed commit buffer
+ROWS = {"tinygpt15m": 125_128, "gpt2-124m": 965_976}
 
 
-def _model(name):
+def _config(name):
+    """A configuration's architecture module and its model."""
     with open(os.path.join(HERE, "configs", name + ".json")) as f:
-        return json.load(f)["model"]
+        cfg = json.load(f)
+    return load_arch(cfg["architecture"]), cfg["model"]
 
 
 def test_tinygpt15m_counts():
-    m = _model("tinygpt15m")
+    arch, m = _config("tinygpt15m")
     # per layer: q,k,v,o 4 x 256^2 and the MLP 2 x 256 x 1024; tied head
     mm = 4 * (4 * 256 * 256 + 2 * 256 * 1024) + 50257 * 256
-    assert flops.matmul_params(m) == mm == 16_011_520
+    assert arch.matmul_params(m) == mm == 16_011_520
     # + LayerNorm scales and biases: 2 per layer x 2 x 256, final 2 x 256
-    assert flops.n_params(m) == mm + 4 * 4 * 256 + 2 * 256 == 16_016_128
+    assert flops.n_params(arch, m) == mm + 4 * 4 * 256 + 2 * 256 \
+        == 16_016_128
     per_token = 6 * mm + 12 * 4 * 8 * 32 * 512
-    assert flops.train_flops_per_token(m, 512) == per_token
+    assert arch.train_flops_per_token(m, 512) == per_token
     assert per_token / 1e9 == pytest.approx(0.1024, abs=1e-3)
 
 
 def test_gpt2_124m_counts():
-    m = _model("gpt2-124m")
+    arch, m = _config("gpt2-124m")
     layer = 4 * 768 * 768 + 2 * 768 * 3072
     mm = 12 * layer + 50257 * 768
-    assert flops.matmul_params(m) == mm
+    assert arch.matmul_params(m) == mm
     # LayerNorms (2 x 2 x 768 per layer, 2 x 768 final), q/k/v biases
     # (3 x 768) and MLP biases (3072 + 768)
     extra = 12 * (4 * 768 + 3 * 768 + 3072 + 768) + 2 * 768
-    assert flops.n_params(m) == mm + extra == 123_644_160
+    assert flops.n_params(arch, m) == mm + extra == 123_644_160
     per_token = 6 * mm + 12 * 12 * 12 * 64 * 512
-    assert flops.train_flops_per_token(m, 512) == per_token
+    assert arch.train_flops_per_token(m, 512) == per_token
     assert per_token / 1e9 == pytest.approx(0.798, abs=1e-3)
 
 
@@ -47,12 +53,13 @@ def test_packed_rows_match_the_programs_layout(name):
     from repro.configs.base import ModelConfig
     from repro.core import packing
     from repro.models import build_model
-    model = build_model(ModelConfig(**_model(name)))
+    arch, m = _config(name)
+    model = build_model(ModelConfig(**m))
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     assert sorted(x.size for x in jax.tree.leaves(shapes)) == sorted(
-        flops.leaf_sizes(_model(name)))
-    assert flops.packed_rows(_model(name)) == packing.build_layout(
-        shapes).n_rows
+        flops.leaf_sizes(arch, m))
+    assert flops.packed_rows(arch, m) == packing.build_layout(
+        shapes).n_rows == ROWS[name]
 
 
 def test_commit_bytes():
