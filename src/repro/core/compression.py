@@ -155,6 +155,11 @@ def roundtrip_with_error_feedback(delta: PyTree, ef: Optional[PyTree],
     decoded is what the synchronizer receives after decompression; new_ef
     accumulates what compression lost (error feedback).
 
+    kind="none" loses nothing, so there is no error to feed back: new_ef
+    is None whatever ``ef`` is, and a worker holds no buffer, as it does
+    with error feedback off or after a crash. (Worker ``ef`` is not part
+    of a checkpoint; ``server_tree`` holds only the server's state.)
+
     layout: optional ``repro.core.packing.BlockLayout`` for ``delta``.
     With kind="int8" it routes the round-trip through the packed buffer
     (O(1) kernel launches); ``ef`` is then a packed (R, 128) buffer, not a
@@ -166,9 +171,8 @@ def roundtrip_with_error_feedback(delta: PyTree, ef: Optional[PyTree],
         decoded_buf, new_ef, nbytes = packed_int8_roundtrip(delta, ef, layout)
         return packing.Packed(decoded_buf), new_ef, nbytes
     if kind == "none":
-        zeros = jax.tree.map(lambda x: jnp.zeros_like(x, jnp.float32), delta)
         nbytes = sum(x.size * 4 for x in jax.tree.leaves(delta))
-        return delta, zeros, nbytes
+        return delta, None, nbytes
     if ef is None:
         ef = jax.tree.map(lambda x: jnp.zeros_like(x, jnp.float32), delta)
     target = jax.tree.map(lambda d, e: d.astype(jnp.float32) + e, delta, ef)

@@ -56,8 +56,13 @@ def run_inner(model: Model, inner_cfg: InnerOptConfig, params: PyTree,
     return InnerResult(params=params, opt=opt, losses=jnp.stack(losses))
 
 
+@jax.jit
 def pseudo_gradient(theta_init: PyTree, theta_final: PyTree) -> PyTree:
-    """Delta = theta_bar - theta_H  (descent displacement, Eq. 3)."""
+    """Delta = theta_bar - theta_H  (descent displacement, Eq. 3), in fp32.
+
+    One program over both trees, traced once per tree structure and
+    dispatched once a round, not one op per leaf. Neither tree is
+    donated: the caller keeps theta_bar."""
     return jax.tree.map(
         lambda a, b: (a.astype(jnp.float32) - b.astype(jnp.float32)),
         theta_init, theta_final)

@@ -130,6 +130,28 @@ def test_compression_reduces_bytes(kind, max_ratio):
     assert np.isfinite(float(jax.tree.leaves(comp.server.state.params)[0].sum()))
 
 
+def test_fp32_exchange_keeps_no_error_feedback():
+    """Under ``compression: "none"`` error feedback leaves every worker
+    without a buffer, and the run equals, bit for bit, the same run with
+    error feedback off: its arrival records (with the update statistics)
+    and its final outer params."""
+    from repro.async_engine.engine import make_engine
+    from repro.telemetry import TelemetryRecorder
+    runs = []
+    for ef in (True, False):
+        eng = make_engine(tiny_run(outer_steps=6, outer=OuterOptConfig(
+            method="heloco", error_feedback=ef)),
+            telemetry=TelemetryRecorder())
+        runs.append((eng, eng.run()))
+    (on, hist_on), (off, hist_off) = runs
+    assert all(w.ef is None for w in on.workers.values())
+    assert hist_on.arrivals == hist_off.arrivals
+    assert all(r["delta_norm"] is not None for r in hist_on.arrivals)
+    for a, b in zip(jax.tree.leaves(on.server.state.params),
+                    jax.tree.leaves(off.server.state.params)):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+
+
 def test_drop_stale_after():
     rc = tiny_run(outer_steps=12,
                   outer=OuterOptConfig(method="heloco", drop_stale_after=1),

@@ -27,7 +27,7 @@ from repro.kernels import packed as pk
 from repro.kernels.tiling import LANES
 from repro.models import build_model
 from repro.optim.adamw import init_adam
-from repro.train.inner import _jitted_step
+from repro.train.inner import _jitted_step, pseudo_gradient
 
 K = 4                      # commit_batch of the fused flush
 BATCH, SEQ = 8, 512        # chip_smoke.py's inner-step shape
@@ -165,3 +165,16 @@ def test_inner_step_compiles(one_chip, model, param_shapes):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 0 < used < HBM_BYTES, used
+
+
+def test_pseudo_gradient_compiles(one_chip, layout, param_shapes):
+    """theta_bar - theta_H is one program over the whole model: its
+    output is the fp32 delta of every parameter (small leaves padded to
+    the chip's tiles), and nothing else."""
+    params = jax.tree.map(lambda s: _on(one_chip, s.shape, s.dtype),
+                          param_shapes)
+    compiled = pseudo_gradient.lower(params, params).compile()
+    mem = compiled.memory_analysis()
+    delta_bytes = 4 * layout.total_elems
+    assert delta_bytes <= mem.output_size_in_bytes < 1.001 * delta_bytes
+    assert mem.alias_size_in_bytes == 0

@@ -124,6 +124,67 @@ def test_error_feedback_converges():
     assert err < 0.25, err
 
 
+@pytest.mark.parametrize("carried", [False, True], ids=["ef_none", "ef_tree"])
+def test_no_compression_feeds_back_no_error(carried):
+    """``"none"`` loses nothing: the delta comes back as it went in and
+    there is no error-feedback tree, whatever the worker carried."""
+    from repro.core.compression import roundtrip_with_error_feedback
+    delta = {"w": jnp.arange(6.0).reshape(2, 3), "b": jnp.ones((4,))}
+    ef = jax.tree.map(jnp.ones_like, delta) if carried else None
+    dec, new_ef, nbytes = roundtrip_with_error_feedback(delta, ef, "none")
+    assert new_ef is None
+    assert dec is delta
+    assert nbytes == 4 * (6 + 4)
+
+
+def _two_trees():
+    rng = np.random.default_rng(3)
+    leaf = lambda shape, dtype: jnp.asarray(rng.normal(size=shape), dtype)
+    a = {"w": leaf((5, 7), jnp.float32), "e": leaf((9,), jnp.bfloat16),
+         "blocks": [leaf((2, 3), jnp.float32), leaf((3,), jnp.bfloat16)]}
+    b = jax.tree.map(lambda x: (x * 0.97 + 1e-3).astype(x.dtype), a)
+    return a, b
+
+
+def test_pseudo_gradient_equals_the_eager_per_leaf_subtract():
+    """One program, the same bits as an eager fp32 subtract per leaf, on
+    fp32 and bf16 leaves."""
+    from repro.train.inner import pseudo_gradient
+    a, b = _two_trees()
+    got = pseudo_gradient(a, b)
+    want = jax.tree.map(
+        lambda x, y: x.astype(jnp.float32) - y.astype(jnp.float32), a, b)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.dtype == jnp.float32
+        assert np.asarray(g).tobytes() == np.asarray(w).tobytes()
+
+
+def test_pseudo_gradient_builds_once_per_tree_structure():
+    """The first call lowers one program for the whole tree; a second
+    round with the same trees lowers none."""
+    from jax import monitoring
+
+    from repro.obs.spans import SpanTracer, program_build_listener
+    from repro.train.inner import pseudo_gradient
+    a, b = _two_trees()
+    b = {**b, "extra": jnp.zeros((1,))}      # a structure no test used yet
+    a = {**a, "extra": jnp.ones((1,))}
+    tr = SpanTracer()
+    listen = program_build_listener(tr)
+    monitoring.register_event_duration_secs_listener(listen)
+    try:
+        builds = []
+        for _ in range(3):
+            jax.block_until_ready(pseudo_gradient(a, b))
+            builds.append([e["args"]["fun"]
+                           for e in tr.to_chrome()["traceEvents"]
+                           if e["name"] == "program_build"])
+    finally:
+        monitoring.unregister_event_duration_listener(listen)
+    assert builds == [["jit(pseudo_gradient)"]] * 3
+
+
 def test_int8_roundtrip_error_bound():
     from repro.core.compression import compress, decompress
     x = {"w": jnp.linspace(-4.0, 4.0, 1000)}
